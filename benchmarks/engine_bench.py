@@ -1295,6 +1295,8 @@ def main():
                          "check at every request retire")
     ap.add_argument("--out", default=None, help="JSON artifact path")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.longctx and not args.tiny:
         _run_longctx(lengths=(1024, 4096, 8192, 16384, 32768), iters=3,
                      out_path=args.out)

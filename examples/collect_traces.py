@@ -8,6 +8,7 @@ import argparse
 
 from repro.core.tracing import collect_traces, save_traces
 from repro.data import make_topic_corpus, sample_prompts
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import train
 from repro.configs import get_reduced
 from repro.models import build_model
@@ -21,6 +22,7 @@ def main():
     ap.add_argument("--train-steps", type=int, default=150)
     ap.add_argument("--out", default="artifacts/my_traces.npz")
     args = ap.parse_args()
+    enable_compile_cache()
 
     params, _ = train("deepseek-v2-lite", reduced=True,
                       steps=args.train_steps, batch_size=16, seq_len=64)

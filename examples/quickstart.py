@@ -20,9 +20,11 @@ from repro.core.predictor_train import train_predictor
 from repro.core.simulator import SimConfig, simulate
 from repro.core.tracing import collect_traces, moe_layer_ids
 from repro.data import lm_batches, make_topic_corpus, sample_prompts
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.training.optimizer import make_adamw
 
+enable_compile_cache()      # this script's body is its main
 t0 = time.time()
 
 # 1. backbone -------------------------------------------------------------

@@ -8,6 +8,7 @@ Run:  PYTHONPATH=src python examples/train_backbone.py --steps 200
 import argparse
 
 from repro.configs import get_reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import train
 
 
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--save", default="artifacts/backbone_100m.npz")
     args = ap.parse_args()
+    enable_compile_cache()
 
     import jax
 

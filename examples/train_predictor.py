@@ -10,6 +10,7 @@ from repro.configs import get_reduced
 from repro.configs.base import PredictorConfig
 from repro.core.predictor_train import train_predictor
 from repro.core.tracing import load_traces, moe_layer_ids
+from repro.launch.compile_cache import enable_compile_cache
 from repro.training import checkpoint as ckpt
 
 
@@ -21,6 +22,7 @@ def main():
     ap.add_argument("--lr", type=float, default=5e-3)
     ap.add_argument("--out", default="artifacts/my_predictor.npz")
     args = ap.parse_args()
+    enable_compile_cache()
 
     traces = load_traces(args.traces)
     n_val = max(1, len(traces) // 5)

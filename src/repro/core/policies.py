@@ -21,6 +21,7 @@ the victim predicted furthest from reuse.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -86,6 +87,22 @@ def _sigmoid(logits: np.ndarray) -> np.ndarray:
     """Per-expert confidence — the same sigmoid probability the paper's
     selection rule thresholds (core/metrics.select_experts)."""
     return 1.0 / (1.0 + np.exp(-np.asarray(logits, np.float64)))
+
+
+def _pow2_ceil(n: int) -> int:
+    return 1 << max(0, n - 1).bit_length()
+
+
+@functools.lru_cache(maxsize=None)
+def _predictor_forward(pcfg):
+    """The jitted predictor forward for one ``PredictorConfig``, shared by
+    every policy instance built on it: the serving engine makes one policy
+    per request, and a jit per instance would compile each shape again for
+    every request."""
+    import jax
+
+    from repro.core.predictor import predictor_apply
+    return jax.jit(lambda pr, e, l, m: predictor_apply(pr, pcfg, e, l, m))
 
 
 class Policy:
@@ -226,14 +243,10 @@ class MoEBeyondPolicy(Policy):
     name = "moe-beyond"
 
     def __init__(self, predictor_params, pcfg, width: Optional[int] = None):
-        import jax
-
-        from repro.core.predictor import predictor_apply
         self.params = predictor_params
         self.pcfg = pcfg
         self.width = width or pcfg.top_k
-        self._apply = jax.jit(
-            lambda pr, e, l, m: predictor_apply(pr, pcfg, e, l, m))
+        self._apply = _predictor_forward(pcfg)
         self._pred: Dict[int, np.ndarray] = {}
 
     def begin_prompt(self, trace):
@@ -311,14 +324,10 @@ class OnlineMoEBeyondPolicy(Policy):
     name = "moe-beyond-online"
 
     def __init__(self, predictor_params, pcfg, width: Optional[int] = None):
-        import jax
-
-        from repro.core.predictor import predictor_apply
         self.params = predictor_params
         self.pcfg = pcfg
         self.width = width or pcfg.top_k
-        self._apply = jax.jit(
-            lambda pr, e, l, m: predictor_apply(pr, pcfg, e, l, m))
+        self._apply = _predictor_forward(pcfg)
         self._emb: list = []
         self._seen_t = -1
 
@@ -334,26 +343,13 @@ class OnlineMoEBeyondPolicy(Policy):
     def predict(self, t, layer):
         return self.predict_scored(t, layer)[0]
 
-    def predict_scored(self, t, layer):
-        import jax.numpy as jnp
-
-        from repro.core.metrics import select_experts
-        pc = self.pcfg
-        # embeddings observed so far (token t itself is appended by the
-        # engine before deeper layers run; fall back to t-1 context)
-        n = min(len(self._emb), pc.max_seq)
-        if n == 0:
-            return np.empty((0,), np.int64), np.empty((0,), np.float64)
-        emb = np.zeros((1, n, pc.token_emb_dim), np.float32)
-        emb[0] = np.stack(self._emb[-n:])
-        logits = np.asarray(self._apply(
-            self.params, jnp.asarray(emb),
-            jnp.full((1, n), layer, jnp.int32),
-            jnp.ones((1, n), bool)))[0, -1, : pc.num_experts]
-        sel = select_experts(logits, self.width, threshold=-1e9)
-        ids = np.nonzero(sel)[0]
-        conf = _sigmoid(logits[ids])
-        return ids, conf
+    def predict_scored(self, t, layer):  # noqa: ARG002
+        # the batched forward with one row: the embeddings observed so far
+        # (token t itself is appended by the engine before deeper layers
+        # run), padded to the power-of-two length bucket, so a growing
+        # prompt compiles once per bucket, not once per length
+        return self.predict_many_layers([self], [layer],
+                                        with_scores=True)[layer][0]
 
     @staticmethod
     def batchable(policies: Sequence["Policy"]) -> bool:
@@ -411,13 +407,14 @@ class OnlineMoEBeyondPolicy(Policy):
         live = [i for i, n in enumerate(ns) if n > 0]
         if not live or not layers:
             return out
-        tb = 1
-        while tb < max(ns[i] for i in live):         # pow-of-two seq bucket
-            tb *= 2
         rows = [(i, layer) for layer in layers for i in live]
-        emb = np.zeros((len(rows), tb, pc.token_emb_dim), np.float32)
-        mask = np.zeros((len(rows), tb), bool)
-        lids = np.zeros((len(rows), tb), np.int32)
+        # power-of-two buckets for both the sequence and the row count
+        # bound recompiles; padded rows are fully masked and never read
+        tb = _pow2_ceil(max(ns[i] for i in live))
+        rb = _pow2_ceil(len(rows))
+        emb = np.zeros((rb, tb, pc.token_emb_dim), np.float32)
+        mask = np.zeros((rb, tb), bool)
+        lids = np.zeros((rb, tb), np.int32)
         for j, (i, layer) in enumerate(rows):
             emb[j, : ns[i]] = np.stack(policies[i]._emb[-ns[i]:])
             mask[j, : ns[i]] = True

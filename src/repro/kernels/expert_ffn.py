@@ -14,6 +14,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, w_ref, wg_ref, wu_ref, wd_ref, o_ref):
@@ -40,16 +41,20 @@ def expert_ffn(x, weights, wg, wu, wd, block_f: int = 512,
                interpret: bool = True):
     """x: (D,); weights: (k,); wg/wu: (k,D,F); wd: (k,F,D) -> (D,)."""
     k, d, f = wg.shape
-    bf = min(block_f, f)
-    while f % bf:                     # largest divisor of f <= block_f
-        bf -= 1
+    # the (D, BF) tiles' lane dim must be a multiple of 128 or all of f:
+    # the largest 128-multiple divisor of f that is <= block_f, else f
+    bf = next((b for b in range(block_f - block_f % 128, 0, -128)
+               if f % b == 0), f)
 
     out = pl.pallas_call(
         _kernel,
         grid=(k, f // bf),
         in_specs=[
             pl.BlockSpec((1, d), lambda ke, fb: (0, 0)),        # x
-            pl.BlockSpec((1, k), lambda ke, fb: (0, 0)),        # weights
+            # combine weights are read one scalar per expert: SMEM, since
+            # a VMEM load at a dynamic lane index does not lower
+            pl.BlockSpec((1, k), lambda ke, fb: (0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((1, d, bf), lambda ke, fb: (ke, 0, fb)),
             pl.BlockSpec((1, d, bf), lambda ke, fb: (ke, 0, fb)),
             pl.BlockSpec((1, bf, d), lambda ke, fb: (ke, fb, 0)),

@@ -7,7 +7,12 @@ logical order, and a ``PrefetchScalarGridSpec`` index map turns the table
 entry into the pool block to fetch, so K/V pages stream from the
 ``(num_blocks, block_size, KVH, hd)`` pool directly — the
 ``(N, W*block_size, ...)`` contiguous copy of the gather path is never
-materialised. Online softmax (running max / sum / accumulator in VMEM
+materialised. The kernel sees the pool as ``(num_blocks, block_size,
+KVH*hd)`` (a free reshape) and fetches one head's ``(block_size, hd)``
+page: a block's last two dims must be multiples of (8, 128) or the whole
+array dims, which a ``(.., 1, hd)`` block over a KVH > 1 axis is not. So on
+a TPU a pool with KVH > 1 needs ``hd % 128 == 0``; KVH == 1 (the MLA
+latent pool) takes any width. Online softmax (running max / sum / accumulator in VMEM
 scratch, rescaled per block) makes the walk single-pass; positions
 ``>= pos+1`` are masked, which also neutralises the scratch block 0 and any
 unreferenced pool block a scratch-padded table names (their logical
@@ -86,8 +91,8 @@ def _online_step(pos_ref, q_ref, o_ref, m_ref, l_ref, acc_ref, k, v, *,
 def _kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
             m_ref, l_ref, acc_ref, *, block_size: int, scale: float,
             dv: int):
-    k = k_ref[0][:, 0].astype(jnp.float32)           # (BS, dk)
-    v = v_ref[0][:, 0, :dv].astype(jnp.float32)      # (BS, dv)
+    k = k_ref[0].astype(jnp.float32)                 # (BS, dk)
+    v = v_ref[0][:, :dv].astype(jnp.float32)         # (BS, dv)
     _online_step(pos_ref, q_ref, o_ref, m_ref, l_ref, acc_ref, k, v,
                  block_size=block_size, scale=scale)
 
@@ -97,7 +102,7 @@ def _kernel_shared(tables_ref, pos_ref, q_ref, k_ref, o_ref,
                    dv: int):
     # MLA latent layout: V is the leading ``dv`` features of the K tile —
     # one page fetch feeds both dots
-    k = k_ref[0][:, 0].astype(jnp.float32)           # (BS, dk)
+    k = k_ref[0].astype(jnp.float32)                 # (BS, dk)
     _online_step(pos_ref, q_ref, o_ref, m_ref, l_ref, acc_ref, k,
                  k[:, :dv], block_size=block_size, scale=scale)
 
@@ -124,20 +129,22 @@ def paged_flash_decode_pallas(q, k_pool, v_pool, tables, pos,
     dv = dvp if dv is None else dv
     scale = dk ** -0.5 if scale is None else scale
 
+    def page(pool):
+        # (nb, BS, KVH, d) -> (nb, BS, KVH*d): head j's page is lane block j
+        return pool.reshape(pool.shape[:2] + (-1,))
+
     in_specs = [
         pl.BlockSpec((1, 1, g, dk), lambda i, j, k, t, p: (i, j, 0, 0)),
-        pl.BlockSpec((1, bs, 1, dk),
-                     lambda i, j, k, t, p: (t[i, k], 0, j, 0)),
+        pl.BlockSpec((1, bs, dk), lambda i, j, k, t, p: (t[i, k], 0, j)),
     ]
-    operands = [q, k_pool]
+    operands = [q, page(k_pool)]
     if v_pool is None:
         body = _kernel_shared
     else:
         body = _kernel
-        in_specs.append(pl.BlockSpec((1, bs, 1, dvp),
-                                     lambda i, j, k, t, p: (t[i, k], 0, j,
-                                                            0)))
-        operands.append(v_pool)
+        in_specs.append(pl.BlockSpec((1, bs, dvp),
+                                     lambda i, j, k, t, p: (t[i, k], 0, j)))
+        operands.append(page(v_pool))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                        # tables, pos
         grid=(n, kvh, w),

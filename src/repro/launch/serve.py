@@ -18,6 +18,7 @@ from repro.core.policies import (GlobalFrequencyPolicy, MoEInfinityPolicy,
                                  OnlineMoEBeyondPolicy, RandomPolicy)
 from repro.core.tracing import collect_traces, moe_layer_ids
 from repro.data import make_topic_corpus, sample_prompts
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import train
 from repro.models import build_model
 from repro.serving.engine import OffloadEngine
@@ -52,6 +53,7 @@ def main():
     ap.add_argument("--train-steps", type=int, default=60)
     ap.add_argument("--n-train-traces", type=int, default=8)
     args = ap.parse_args()
+    enable_compile_cache()
 
     params, _ = train(args.arch, reduced=True, steps=args.train_steps,
                       batch_size=16, seq_len=64)

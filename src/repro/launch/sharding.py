@@ -183,7 +183,6 @@ def expert_dispatch_ffn(mesh, wg, wu, wd, x_send, eid_send):
     :func:`repro.models.moe.expert_group_ffn`). f32 accumulation, output
     in ``x_send.dtype``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax import lax
 
     s_mesh = _axis_size(mesh, "expert")
@@ -217,7 +216,7 @@ def expert_dispatch_ffn(mesh, wg, wu, wd, x_send, eid_send):
                             tiled=True)
         return yr[None]
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("expert"), P("expert"), P("expert"),
                   P("expert"), P("expert")),

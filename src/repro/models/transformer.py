@@ -312,14 +312,17 @@ def stack_apply(params, cfg, x, positions, mode: str,
 # ---------------------------------------------------------------------------
 # LM wrapper (embeddings + stack + head), incl. stubbed modality frontends
 
-def lm_init(key, cfg) -> Params:
+def lm_init(key, cfg, stack_fn=stack_init) -> Params:
+    """The whole LM's params. ``stack_fn(key, cfg, dtype)`` builds the
+    decoder stack; the offload engine passes one that keeps each routed
+    expert stack on the host (serving/engine.init_offload_params)."""
     dtype = dtype_of(cfg)
     k_emb, k_stack, k_head, k_fe = jax.random.split(key, 4)
     p: Params = {
         "tok_emb": (jax.random.normal(k_emb, (cfg.vocab_size, cfg.d_model),
                                       jnp.float32) * 0.02).astype(dtype),
         "final_ln": rms_norm_init(cfg.d_model, dtype),
-        "stack": stack_init(k_stack, cfg, dtype),
+        "stack": stack_fn(k_stack, cfg, dtype),
     }
     if not cfg.tie_embeddings:
         p["head"] = dense_init(k_head, cfg.d_model, cfg.vocab_size, dtype)

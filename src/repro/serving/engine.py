@@ -55,6 +55,66 @@ from repro.serving.telemetry import (NULL_TELEMETRY, PID_ENGINE,
                                      PID_REQUESTS)
 
 
+# the routed-expert stacks of a MoE layer's "moe" dict: they live in the
+# host expert store, and on the device only in tier-0 slots
+ROUTED = ("w_gate", "w_up", "w_down")
+
+# one compiled initializer per layer signature: a MoE layer's expert stacks
+# are drawn by one device program and leave the device right after
+_block_init = jax.jit(T.block_init, static_argnums=(1, 2, 3, 4))
+
+
+def init_offload_params(cfg, key):
+    """``transformer.lm_init(key, cfg)`` for the offload engine: the same
+    key schedule and tree, but every MoE layer's routed-expert stacks are
+    drawn on the device one layer at a time, copied to the host and freed
+    on the device. Scanned layers' stacks are filled into one preallocated
+    host array per pattern position, so neither side ever holds more than
+    one layer's experts beyond the host store itself. Everything else is
+    on the device."""
+    return T.lm_init(key, cfg, stack_fn=_offload_stack_init)
+
+
+def _offload_stack_init(key, cfg, dtype):
+    """``transformer.stack_init``'s layout and keys, routed stacks on the
+    host (see ``init_offload_params``)."""
+    kinds = cfg.layer_kinds()
+    n_head, n_groups, n_tail = T._layer_split(cfg)
+    pat = len(cfg.block_pattern)
+    keys = jax.random.split(key, cfg.num_layers)
+
+    def block(li, host=None, g=0):
+        p = _block_init(keys[li], cfg, kinds[li], T._layer_is_moe(cfg, li),
+                        dtype)
+        if "moe" not in p:
+            return p
+        moe = dict(p["moe"])
+        for name in ROUTED:
+            w = np.asarray(moe.pop(name))          # device -> host
+            if host is None:
+                moe[name] = w
+            else:
+                if name not in host:
+                    host[name] = np.empty((n_groups,) + w.shape, w.dtype)
+                host[name][g] = w
+        return dict(p, moe=moe)
+
+    head = [block(i) for i in range(n_head)]
+    scan = []
+    for j in range(pat):
+        host: Dict[str, np.ndarray] = {}
+        per_group = [block(n_head + g * pat + j, host, g)
+                     for g in range(n_groups)]
+        st = (jax.tree.map(lambda *xs: jnp.stack(xs), *per_group)
+              if n_groups else {})
+        if host:
+            st["moe"].update(host)
+        scan.append(st)
+    tail_base = n_head + n_groups * pat
+    tail = [block(tail_base + i) for i in range(n_tail)]
+    return {"head": head, "scan": tuple(scan), "tail": tail}
+
+
 def unstack_layers(cfg, params) -> List[dict]:
     """Per-layer params list from the scan-stacked pytree."""
     st = params["stack"]
@@ -66,6 +126,14 @@ def unstack_layers(cfg, params) -> List[dict]:
             layers.append(jax.tree.map(lambda x, g=g: x[g], st["scan"][j]))
     layers.extend(st["tail"])
     return layers
+
+
+def _without_routed(lp: dict) -> dict:
+    """A layer's params minus its routed-expert stacks."""
+    if "moe" not in lp:
+        return lp
+    return dict(lp, moe={k: v for k, v in lp["moe"].items()
+                         if k not in ROUTED})
 
 
 def sample_token(logits: np.ndarray, temperature: float,
@@ -235,8 +303,7 @@ class DecodeCore:
         assert cfg.moe is not None, "offload engine needs an MoE backbone"
         self.cfg = cfg
         self.model = model
-        self.params = params
-        self.layers = unstack_layers(cfg, params)
+        layers = unstack_layers(cfg, params)
         self.kinds = cfg.layer_kinds()
         self.moe_layers = moe_layer_ids(cfg)
         self.moe_index = {li: i for i, li in enumerate(self.moe_layers)}
@@ -261,10 +328,21 @@ class DecodeCore:
         # MoE ordinal, consumed by _moe_units for the predictor scoreboard
         self._pred_d0: Dict[int, set] = {}
 
-        # host store gets the routed-expert weights; everything else stays
-        # in self.layers (device). ``tiers`` (a TierConfig) swaps the
-        # single-host store for the device/host/peer/disk hierarchy.
-        store_layers = [self.layers[li]["moe"] for li in self.moe_layers]
+        # host store gets the routed-expert weights (host arrays, whatever
+        # side ``params`` held them on); everything else is in self.layers
+        # on the device, which holds routed experts only in tier-0 slots.
+        # ``tiers`` (a TierConfig) swaps the single-host store for the
+        # device/host/peer/disk hierarchy.
+        store_layers = [{k: np.asarray(layers[li]["moe"][k]) for k in ROUTED}
+                        for li in self.moe_layers]
+        self.layers = [jax.device_put(_without_routed(lp)) for lp in layers]
+        del layers
+        self._tok_emb = jax.device_put(params["tok_emb"])
+        # the unembedding program's whole input: the final norm and the
+        # output matrix (the tied embedding or the separate head)
+        self._head_params = jax.device_put(
+            {k: params[k] for k in ("final_ln", "head" if "head" in params
+                                    else "tok_emb")})
         # replacement="learned": one ReuseDistanceScorer shared by the
         # tier-0 slot cache and the store's tier-1 cache. _submit_prefetch
         # feeds it the raw (pre-gating) multi-horizon predictions and
@@ -429,9 +507,9 @@ class DecodeCore:
             return x + ffn_apply(lp["ffn"], h, cfg.ffn_kind)
 
         @jax.jit
-        def router_fn(lp, x):
-            h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            w, idx, probs = moe_mod.route(lp["moe"], cfg, h)
+        def router_fn(ln2, w_router, x):
+            h = rms_norm(x, ln2, cfg.norm_eps)
+            w, idx, _ = moe_mod.route({"w_router": w_router}, cfg, h)
             return h, w, idx
 
         @jax.jit
@@ -456,8 +534,8 @@ class DecodeCore:
             return out
 
         @jax.jit
-        def unembed_fn(params, x):
-            return T.unembed(params, cfg, x)
+        def unembed_fn(head_params, x):
+            return T.unembed(head_params, cfg, x)
 
         self._embed = embed_fn
         self._embed_seq = embed_seq_fn
@@ -823,7 +901,7 @@ class DecodeCore:
 
         t_wall = time.perf_counter()
         self._step_advanced = 0.0
-        x = self._embed(self.params["tok_emb"], toks_p)
+        x = self._embed(self._tok_emb, toks_p)
         experts_out = [[] for _ in range(n)]
         # double-buffer: predictions for the lookahead window starting at
         # the first MoE layer go onto the channels now, hiding behind the
@@ -842,7 +920,7 @@ class DecodeCore:
             self._advance(li, 0)
             if li in self.moe_index:
                 mi = self.moe_index[li]
-                h, w, idx = self._router(lp, x)
+                h, w, idx = self._router(lp["ln2"], lp["moe"]["w_router"], x)
                 idx_np = np.asarray(idx)[:, 0, :]               # (nb, k)
                 x, gts = self._moe_units(mi, lp, h, w.astype(x.dtype), x,
                                          idx_np, n)
@@ -855,7 +933,7 @@ class DecodeCore:
             elif "ffn" in lp:
                 x = self._dense_ffn(lp, x)
                 self._advance(li, 1)
-        logits = np.asarray(self._unembed(self.params, x))[:n, 0]
+        logits = np.asarray(self._unembed(self._head_params, x))[:n, 0]
         self.stats.tokens += n
         self.stats.steps += 1
         wall = time.perf_counter() - t_wall
@@ -904,7 +982,7 @@ class DecodeCore:
 
         t_wall = time.perf_counter()
         self._step_advanced = 0.0
-        x = self._embed_seq(self.params["tok_emb"], toks_p)      # (1,cb,D)
+        x = self._embed_seq(self._tok_emb, toks_p)               # (1,cb,D)
         experts_out: List[List[np.ndarray]] = []
         self._submit_prefetch(policy, [rid], [t0], 0)
         for li in range(cfg.num_layers):
@@ -918,7 +996,8 @@ class DecodeCore:
             self._advance(li, 0)
             if li in self.moe_index:
                 mi = self.moe_index[li]
-                h, w, idx = self._router(lp, x)                 # (1,cb,...)
+                h, w, idx = self._router(lp["ln2"], lp["moe"]["w_router"],
+                                         x)                     # (1,cb,...)
                 idx_np = np.asarray(idx)[0]                     # (cb, k)
                 # chunk tokens become the expert units: same gather path,
                 # same pinning discipline as decode lanes
@@ -934,7 +1013,7 @@ class DecodeCore:
             elif "ffn" in lp:
                 x = self._dense_ffn(lp, x)
                 self._advance(li, 1)
-        logits = np.asarray(self._unembed(self.params, x))[0, :n]
+        logits = np.asarray(self._unembed(self._head_params, x))[0, :n]
         self.stats.tokens += n
         self.stats.prefill_tokens += n
         self.stats.prefill_chunks += 1
@@ -967,7 +1046,6 @@ class OffloadEngine:
                                telemetry=telemetry)
         self.cfg = self.core.cfg
         self.model = model
-        self.params = params
         self.policy = policy
         # a single in-flight request may share one stateful instance
         self._prp = (None if policy is None

@@ -27,8 +27,10 @@ stall degenerates to the blocking demand-fetch model
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Dict, Iterable, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -269,6 +271,15 @@ class OverlapTracker:
         return stall
 
 
+@partial(jax.jit, donate_argnums=(0, 1, 2))
+def _write_slot(wg_buf, wu_buf, wd_buf, slot, wg, wu, wd):
+    """Copy one expert's weights into row ``slot`` of the three slot
+    buffers. The buffers are donated, so the write lands in place instead
+    of copying the whole ``(slots, d, f)`` stack per fill."""
+    return (wg_buf.at[slot].set(wg), wu_buf.at[slot].set(wu),
+            wd_buf.at[slot].set(wd))
+
+
 class SlotBuffer:
     """Fixed-capacity device buffer of expert slots + host slot table.
 
@@ -324,9 +335,7 @@ class SlotBuffer:
         slot = self._free.pop()
         self.slot_of[key] = slot
         (wg, wu, wd), info = self.store.fetch(key)
-        self.w_gate = self.w_gate.at[slot].set(jnp.asarray(wg))
-        self.w_up = self.w_up.at[slot].set(jnp.asarray(wu))
-        self.w_down = self.w_down.at[slot].set(jnp.asarray(wd))
+        self._write(slot, wg, wu, wd)
         nbytes = wg.nbytes + wu.nbytes + wd.nbytes
         dur = (info.duration if info.duration is not None
                else nbytes / self.host_bw)
@@ -355,11 +364,13 @@ class SlotBuffer:
         assert 0 <= idx < self.ship_slots, \
             f"ship row {idx} out of range (ship_slots={self.ship_slots})"
         slot = self.n_slots + idx
-        wg, wu, wd = weights
-        self.w_gate = self.w_gate.at[slot].set(jnp.asarray(wg))
-        self.w_up = self.w_up.at[slot].set(jnp.asarray(wu))
-        self.w_down = self.w_down.at[slot].set(jnp.asarray(wd))
+        self._write(slot, *weights)
         return slot
+
+    def _write(self, slot: int, wg, wu, wd) -> None:
+        self.w_gate, self.w_up, self.w_down = _write_slot(
+            self.w_gate, self.w_up, self.w_down, np.asarray(slot, np.int32),
+            wg, wu, wd)
 
     def gather(self, keys) -> tuple:
         """Return (k, ...) stacked expert weights for resident keys."""

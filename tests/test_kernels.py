@@ -30,6 +30,7 @@ def test_topk_gating(t, e, k, dtype):
 
 @pytest.mark.parametrize("k,d,f", [
     (1, 128, 128), (2, 128, 256), (6, 256, 512), (4, 512, 1024),
+    (2, 128, 1408),      # DeepSeek-V2-Lite's expert width: 11 128-blocks
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_expert_ffn(k, d, f, dtype):

@@ -474,3 +474,34 @@ def test_predict_many_layers_with_scores_matches_scalar():
             np.testing.assert_allclose(np.asarray(conf_f)[order_f],
                                        np.asarray(conf_s)[order_s],
                                        rtol=1e-5, atol=1e-6)
+
+
+def test_online_predictor_compiles_once_per_bucket():
+    """Per-request policies on one predictor share its compiled forward,
+    and a growing prompt compiles once per power-of-two length bucket: the
+    serving engine makes a policy per request and predicts at every
+    prefill chunk."""
+    import jax
+
+    from repro.configs.base import PredictorConfig
+    from repro.core.policies import (OnlineMoEBeyondPolicy,
+                                     _predictor_forward)
+    from repro.core.predictor import predictor_init
+
+    pc = PredictorConfig(token_emb_dim=16, num_model_layers=3, num_experts=8,
+                         layer_emb_dim=8, d_model=16, num_layers=2,
+                         num_heads=2, d_ff=32, max_seq=32, top_k=3)
+    pp = predictor_init(jax.random.PRNGKey(0), pc)
+    rng = np.random.default_rng(2)
+    pols = [OnlineMoEBeyondPolicy(pp, pc) for _ in range(3)]
+    fwd = _predictor_forward(pc)
+    assert all(p._apply is fwd for p in pols)
+    before = fwd._cache_size()
+    for p in pols:
+        p.begin_prompt()
+        for t in range(16):
+            p.observe(t, 0, [1], rng.normal(size=16).astype(np.float32))
+            if t >= 8:                           # lengths 9..16: bucket 16
+                ids, conf = p.predict_scored(t, 1)
+                assert len(ids) == pc.top_k and len(conf) == pc.top_k
+    assert fwd._cache_size() - before <= 1

@@ -107,3 +107,56 @@ def test_engine_pallas_expert_backend(backbone):
         l1, s1, _ = eng_jnp.decode_token(s1, tok)
         l2, s2, _ = eng_pal.decode_token(s2, tok)
         np.testing.assert_allclose(l1, l2, rtol=2e-4, atol=2e-4)
+
+
+def test_host_only_routed_experts_match_decode_step():
+    """``init_offload_params`` draws ``lm_init``'s tree with every routed
+    expert stack on the host; an engine built from it keeps routed experts
+    on the device only in tier-0 slots, and at full capacity its greedy
+    streams equal the monolithic ``model.decode_step``'s."""
+    from repro.configs import get_reduced
+    from repro.models import build_model
+    from repro.serving.engine import ROUTED, init_offload_params
+    from repro.serving.scheduler import BatchedOffloadEngine
+
+    cfg = get_reduced("deepseek-v2-lite")
+    model = build_model(cfg)
+    key = jax.random.PRNGKey(7)
+    params = init_offload_params(cfg, key)
+    for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+        names = [getattr(k, "key", None) for k in path]
+        routed = names[-1] in ROUTED and names[-2] == "moe"
+        assert isinstance(leaf, np.ndarray) == routed, names
+    # the same draw as the model's own init, layer for layer, up to one
+    # rounding step of the leaf's dtype (the layers are drawn by a jitted
+    # program, the model's init runs op by op)
+    def same(a, b):
+        eps = float(jnp.finfo(b.dtype).eps)
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), rtol=eps,
+                                   atol=eps * 1e-2)
+    jax.tree.map(same, params, model.init(key))
+
+    n_total = len(moe_layer_ids(cfg)) * cfg.moe.num_experts
+    eng = BatchedOffloadEngine(model, params, None, n_total, max_batch=2)
+    for lp in eng.core.layers:
+        assert not set(ROUTED) & set(lp.get("moe", {}))
+        assert all(isinstance(x, jax.Array) for x in jax.tree.leaves(lp))
+    prompts = [[3, 17, 5, 250, 9], [99, 7, 42]]
+    max_new, cache_len = 5, 16
+    got = eng.generate(prompts, max_new=max_new, cache_len=cache_len)
+
+    dev = jax.device_put(params)
+    step_fn = jax.jit(model.decode_step)
+    for prompt, stream in zip(prompts, got):
+        state = model.init_decode_state(1, cache_len)
+        cur, ref = prompt[0], []
+        for t in range(len(prompt) + max_new):
+            logits, state = step_fn(
+                dev, state, {"tokens": jnp.full((1, 1), cur, jnp.int32)})
+            if t + 1 < len(prompt):
+                cur = prompt[t + 1]
+            else:
+                cur = int(np.argmax(np.asarray(logits)[0]))
+                ref.append(cur)
+        assert stream == ref
