@@ -1,0 +1,7 @@
+"""Expert store: share of the traced window in which the device sat idle
+while the innermost program span was ``store.fill``."""
+from benchmarks.chip.spans import idle_pct
+
+
+def read(ctx):
+    return idle_pct(ctx, "store.fill")

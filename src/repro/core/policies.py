@@ -98,11 +98,16 @@ def _predictor_forward(pcfg):
     """The jitted predictor forward for one ``PredictorConfig``, shared by
     every policy instance built on it: the serving engine makes one policy
     per request, and a jit per instance would compile each shape again for
-    every request."""
+    every request. The program is named ``predictor_forward`` on the
+    device."""
     import jax
 
     from repro.core.predictor import predictor_apply
-    return jax.jit(lambda pr, e, l, m: predictor_apply(pr, pcfg, e, l, m))
+
+    def predictor_forward(pr, e, l, m):
+        return predictor_apply(pr, pcfg, e, l, m)
+
+    return jax.jit(predictor_forward)
 
 
 class Policy:

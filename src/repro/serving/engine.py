@@ -653,12 +653,13 @@ class DecodeCore:
             return
         scored = (self.scorer is not None
                   or self._conf_threshold is not None)
-        if scored:
-            preds = policy.predict_batch_multi_scored(rids, ts, mis)
-        elif len(mis) == 1:
-            preds = {mis[0]: policy.predict_batch(rids, ts, mis[0])}
-        else:
-            preds = policy.predict_batch_multi(rids, ts, mis)
+        with self.tel.span(PID_ENGINE, 1, "predictor.predict"):
+            if scored:
+                preds = policy.predict_batch_multi_scored(rids, ts, mis)
+            elif len(mis) == 1:
+                preds = {mis[0]: policy.predict_batch(rids, ts, mis[0])}
+            else:
+                preds = policy.predict_batch_multi(rids, ts, mis)
         # pass 1: record the WHOLE window into the scorer and decide what
         # fits, before any insertion — the d0 prefetch's evictions must see
         # the deeper layers' predicted distances, not last cycle's stale
@@ -731,9 +732,6 @@ class DecodeCore:
         if tel_on:
             self._pred_d0[mis[0]] = raw0
             submitted = sum(len(keys) for _, rows in plan for keys in rows)
-            self.tel.counter("prefetch.submitted", submitted)
-            if clamped:
-                self.tel.counter("prefetch.clamps")
             self.tel.instant(PID_ENGINE, 1, "prefetch",
                              {"li_from": li_from, "window": len(mis),
                               "submitted": submitted, "clamped": clamped,
@@ -883,6 +881,13 @@ class DecodeCore:
         (logits (N, V) f32, new caches, per-request per-MoE-layer
         ground-truth sets).
         """
+        with self.tel.span(PID_ENGINE, 1, "decode_step",
+                           {"batch": len(rows)}):
+            return self._decode_step(caches, rows, pos, tokens, policy,
+                                     rids, tables)
+
+    def _decode_step(self, caches, rows, pos, tokens, policy, rids,
+                     tables):
         cfg = self.cfg
         n = len(rows)
         ts = list(pos)
@@ -921,7 +926,8 @@ class DecodeCore:
             if li in self.moe_index:
                 mi = self.moe_index[li]
                 h, w, idx = self._router(lp["ln2"], lp["moe"]["w_router"], x)
-                idx_np = np.asarray(idx)[:, 0, :]               # (nb, k)
+                with self.tel.span(PID_ENGINE, 1, "driver.route_sync"):
+                    idx_np = np.asarray(idx)[:, 0, :]           # (nb, k)
                 x, gts = self._moe_units(mi, lp, h, w.astype(x.dtype), x,
                                          idx_np, n)
                 if policy is not None:
@@ -933,7 +939,8 @@ class DecodeCore:
             elif "ffn" in lp:
                 x = self._dense_ffn(lp, x)
                 self._advance(li, 1)
-        logits = np.asarray(self._unembed(self._head_params, x))[:n, 0]
+        with self.tel.span(PID_ENGINE, 1, "driver.logits_sync"):
+            logits = np.asarray(self._unembed(self._head_params, x))[:n, 0]
         self.stats.tokens += n
         self.stats.steps += 1
         wall = time.perf_counter() - t_wall
@@ -941,8 +948,6 @@ class DecodeCore:
         self._sync_stats()
         if self.tel.enabled:
             t0_s = self.tel.rel(t_wall)
-            self.tel.complete(PID_ENGINE, 1, "decode_step", t0_s, wall,
-                              {"batch": n})
             self.tel.histogram("step.wall_s", wall)
             for rid, p in zip(rids, pos):
                 tid = int(rid) + 1
@@ -969,6 +974,12 @@ class DecodeCore:
         a per-MoE-layer list of per-token ground-truth expert-id arrays,
         the raw material the prefix cache records for activation replay.
         """
+        with self.tel.span(PID_ENGINE, 1, "prefill_chunk",
+                           {"rid": int(rid), "n": len(tokens)}):
+            return self._prefill_chunk(caches, table, t0, tokens, policy,
+                                       rid)
+
+    def _prefill_chunk(self, caches, table, t0, tokens, policy, rid):
         assert self.chunk_prefill_ok, \
             "chunked prefill needs a global/mla-only stack"
         cfg = self.cfg
@@ -998,7 +1009,8 @@ class DecodeCore:
                 mi = self.moe_index[li]
                 h, w, idx = self._router(lp["ln2"], lp["moe"]["w_router"],
                                          x)                     # (1,cb,...)
-                idx_np = np.asarray(idx)[0]                     # (cb, k)
+                with self.tel.span(PID_ENGINE, 1, "driver.route_sync"):
+                    idx_np = np.asarray(idx)[0]                 # (cb, k)
                 # chunk tokens become the expert units: same gather path,
                 # same pinning discipline as decode lanes
                 hu = h[0][:, None, :]
@@ -1013,7 +1025,8 @@ class DecodeCore:
             elif "ffn" in lp:
                 x = self._dense_ffn(lp, x)
                 self._advance(li, 1)
-        logits = np.asarray(self._unembed(self._head_params, x))[0, :n]
+        with self.tel.span(PID_ENGINE, 1, "driver.logits_sync"):
+            logits = np.asarray(self._unembed(self._head_params, x))[0, :n]
         self.stats.tokens += n
         self.stats.prefill_tokens += n
         self.stats.prefill_chunks += 1
@@ -1026,8 +1039,6 @@ class DecodeCore:
             self.tel.ensure_track(PID_REQUESTS, tid, f"req {rid}")
             self.tel.complete(PID_REQUESTS, tid, "prefill", t0_s, wall,
                               {"t0": int(t0), "n": n})
-            self.tel.complete(PID_ENGINE, 1, "prefill_chunk", t0_s, wall,
-                              {"rid": int(rid), "n": n})
             self.tel.histogram("prefill.wall_s", wall)
         return logits, caches, experts_out
 

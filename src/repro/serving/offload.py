@@ -216,8 +216,6 @@ class OverlapTracker:
             self.tel.complete(PID_CHANNELS, tier, name, fresh - dur, dur,
                               {"key": str(key), "bytes": int(nbytes),
                                "tier": tier})
-            self.tel.counter("ship.bytes" if tier == CHANNEL_SHIP
-                             else "fetch.bytes", int(nbytes))
         return False
 
     def _prune_wire(self) -> None:
@@ -251,7 +249,6 @@ class OverlapTracker:
         self.stall_by_tier[crit_tier] = (
             self.stall_by_tier.get(crit_tier, 0.0) + stall)
         if self.tel.enabled and stall > 0:
-            self.tel.counter("stall.s", stall)
             self.tel.instant(PID_ENGINE, 1, "stall",
                              {"stall_s": stall,
                               "critical_tier": crit_tier})
@@ -304,7 +301,7 @@ class SlotBuffer:
     def __init__(self, store: HostExpertStore, n_slots: int,
                  host_bw: float = 100e9,
                  tracker: Optional[OverlapTracker] = None,
-                 ship_slots: int = 0):
+                 ship_slots: int = 0, telemetry=None):
         lp = store.layers[0]
         e, d, f = lp["w_gate"].shape
         self.store = store
@@ -312,6 +309,7 @@ class SlotBuffer:
         self.ship_slots = ship_slots
         self.host_bw = host_bw
         self.tracker = tracker
+        self.tel = telemetry if telemetry is not None else NULL_TELEMETRY
         rows = n_slots + ship_slots
         self.w_gate = jnp.zeros((rows, d, f), lp["w_gate"].dtype)
         self.w_up = jnp.zeros((rows, d, f), lp["w_up"].dtype)
@@ -334,8 +332,9 @@ class SlotBuffer:
     def fill(self, key: Key) -> None:
         slot = self._free.pop()
         self.slot_of[key] = slot
-        (wg, wu, wd), info = self.store.fetch(key)
-        self._write(slot, wg, wu, wd)
+        with self.tel.span(PID_ENGINE, 1, "store.fill"):
+            (wg, wu, wd), info = self.store.fetch(key)
+            self._write(slot, wg, wu, wd)
         nbytes = wg.nbytes + wu.nbytes + wd.nbytes
         dur = (info.duration if info.duration is not None
                else nbytes / self.host_bw)
@@ -395,9 +394,9 @@ def make_offload_cache(store: HostExpertStore, capacity: int,
     from reuse. ``ship_slots`` sizes the buffer's ephemeral
     compute-dispatch rows (see :class:`SlotBuffer`). ``telemetry`` (a
     ``serving.telemetry.Telemetry``) lets the cache report evictions with
-    victim provenance."""
+    victim provenance and the buffer time each fill (``store.fill``)."""
     buf = SlotBuffer(store, capacity, host_bw, tracker,
-                     ship_slots=ship_slots)
+                     ship_slots=ship_slots, telemetry=telemetry)
     cache = ExpertCache(capacity, eviction, on_evict=buf.release,
                         on_insert=buf.fill, scorer=scorer,
                         telemetry=telemetry)

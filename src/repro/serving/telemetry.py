@@ -20,6 +20,11 @@ Design contract (pinned by ``tests/test_telemetry.py``):
   to host-side lists. It never touches engine state, RNG streams or
   jitted programs — token streams and ``EngineStats`` are bit-identical
   with telemetry on or off.
+* **One place for spans.** Every ``span()`` that is live also opens a
+  ``jax.profiler.TraceAnnotation`` of its name, so a profiler session
+  (``jax.profiler.trace``) shows the program's spans on the host line of
+  its trace, on the same clock as the device's ops. Off-mode builds no
+  annotation. The serving code never calls ``jax.profiler`` itself.
 * **Registered metric names only.** Every ``counter``/``gauge``/
   ``histogram`` name must exist in the module-level ``METRICS`` catalogue;
   unknown names raise (and the stats-registration lint flags literal
@@ -31,7 +36,9 @@ Tracks are ``(pid, tid)`` pairs in Chrome ``trace_event`` terms:
 chunks, decode steps, preempt/resume, retire — wall clock),
 ``PID_CHANNELS`` one thread per ``OverlapTracker`` fetch/ship channel
 (modeled transfer timeline) and ``PID_ENGINE`` the engine-wide driver
-events (prefetch submissions, evictions, stalls). ``to_chrome_trace()``
+events (decode steps and prefill chunks with the expert fills,
+predictions and host syncs inside them; prefetch submissions, evictions,
+stalls). ``to_chrome_trace()``
 emits the whole thing as ``trace_event`` JSON that loads directly in
 ``ui.perfetto.dev``; ``series()``/``scoreboard()`` are the rolling
 time-series view (``tools/check_trace.py`` validates both).
@@ -41,6 +48,8 @@ from __future__ import annotations
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # Chrome-trace process ids — one per timebase/subsystem. Request and
 # engine tracks run on the wall clock (``Telemetry.now``); channel tracks
@@ -74,12 +83,6 @@ METRICS = {
                       "(peer/disk)",
     "cache.evictions": "tier-0 slot evictions (provenance in the "
                        "eviction instant events)",
-    "prefetch.submitted": "predicted keys inserted by _submit_prefetch",
-    "prefetch.clamps": "lookahead windows truncated by the deep-prefetch "
-                       "fit clamp (EngineStats.horizon_clamps mirror)",
-    "fetch.bytes": "weight bytes put on a fetch channel (per transfer)",
-    "ship.bytes": "activation bytes put on the ship channel",
-    "stall.s": "un-overlapped transfer stall charged at a wait (seconds)",
     "kv.blocks_in_use": "KV pool blocks currently allocated (gauge)",
     "prefix.adopted_blocks": "prefix-cache blocks adopted at admission "
                              "or chunk-boundary extension",
@@ -154,8 +157,12 @@ _NULL_SPAN = _NullSpan()
 
 
 class _LiveSpan:
-    """Context manager emitting a balanced B/E pair on a track."""
-    __slots__ = ("_tel", "_pid", "_tid", "_name", "_args")
+    """Context manager emitting a balanced B/E pair on a track, and a
+    ``jax.profiler.TraceAnnotation`` of the same name around the body: a
+    profiler session running meanwhile puts the span on the calling
+    thread's host line, on the device trace's clock. The annotation
+    carries the name only; the args go to the Chrome export."""
+    __slots__ = ("_tel", "_pid", "_tid", "_name", "_args", "_ann")
 
     def __init__(self, tel, pid, tid, name, args):
         self._tel, self._pid, self._tid = tel, pid, tid
@@ -163,9 +170,12 @@ class _LiveSpan:
 
     def __enter__(self):
         self._tel.begin(self._pid, self._tid, self._name, self._args)
+        self._ann = TraceAnnotation(self._name)   # starts when built
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
         self._tel.end(self._pid, self._tid, self._name)
         return False
 

@@ -3,6 +3,7 @@ zero-overhead-off contract (token streams and deterministic engine stats
 pinned identical with telemetry on vs off), the predictor scoreboard's
 exact aggregation, and Chrome-trace export validated by the same checker
 CI runs (``tools/check_trace.py``)."""
+import glob
 import os
 import sys
 
@@ -15,6 +16,7 @@ from repro.core.policies import NextLayerAllPolicy
 from repro.core.tracing import moe_layer_ids
 from repro.serving.config import ServeConfig
 from repro.serving.scheduler import BatchedOffloadEngine
+from repro.serving import telemetry as telemetry_mod
 from repro.serving.telemetry import (METRICS, NULL_TELEMETRY, PID_ENGINE,
                                      PID_REQUESTS, Telemetry)
 
@@ -231,6 +233,70 @@ def test_chrome_trace_roundtrips_through_validator(on_off):
     names = ct.track_names(doc["traceEvents"])
     assert "requests" in names and "engine" in names
     assert len(names["requests"]) == len(PROMPTS)
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+PROGRAM_SPANS = {"decode_step", "prefill_chunk", "store.fill",
+                 "predictor.predict", "driver.route_sync",
+                 "driver.logits_sync"}
+
+
+def _profiled_spans(backbone, tmp_path):
+    """(name, start_ns, end_ns) of the program's spans in a CPU profiler
+    trace of one telemetry-on run, read back from the trace file."""
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        _run(backbone, Telemetry())
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    return [(e.name, e.start_ns, e.end_ns)
+            for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name in PROGRAM_SPANS]
+
+
+def test_live_spans_land_in_the_profiler_trace_nested(backbone, tmp_path):
+    """Each live span is also a profiler annotation: the decode step holds
+    the fills, the prediction and both syncs, and any two program spans
+    either nest or do not meet."""
+    spans = _profiled_spans(backbone, tmp_path)
+    steps = [(a, b) for n, a, b in spans if n == "decode_step"]
+    assert steps and any(n == "prefill_chunk" for n, _, _ in spans)
+    for name in ("store.fill", "predictor.predict", "driver.route_sync",
+                 "driver.logits_sync"):
+        assert any(sa <= a and b <= sb for n, a, b in spans if n == name
+                   for sa, sb in steps), name
+    spans.sort(key=lambda s: (s[1], -s[2]))
+    for i, (_, a, b) in enumerate(spans):
+        for _, c, d in spans[i + 1:]:
+            if c >= b:
+                break
+            assert d <= b, "program spans overlap without nesting"
+
+
+def test_off_mode_builds_no_trace_annotation(backbone, monkeypatch):
+    """A whole telemetry-off run constructs no profiler annotation; the
+    counting stand-in does see a live span's."""
+    import jax
+    built = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            built.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(telemetry_mod, "TraceAnnotation", Counting)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    _run(backbone, None)
+    assert built == []
+    with Telemetry().span(PID_ENGINE, 1, "x"):
+        pass
+    assert built == ["x"]
 
 
 def test_validator_catches_broken_traces():
