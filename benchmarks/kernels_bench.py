@@ -34,21 +34,27 @@ def run(log=print):
     out["topk_gating_ref_us"] = us_ref
     out["topk_gating_pallas_interp_us"] = us_pal
 
-    # batch-1 decode expert FFN at Lite scale
-    k, d, f = 6, 2048, 1408
-    x = jnp.asarray(rng.normal(size=(d,)), jnp.float32)
-    w = jnp.asarray(rng.random(k), jnp.float32)
-    wg = jnp.asarray(rng.normal(size=(k, d, f)) * 0.02, jnp.float32)
-    wu = jnp.asarray(rng.normal(size=(k, d, f)) * 0.02, jnp.float32)
-    wd = jnp.asarray(rng.normal(size=(k, f, d)) * 0.02, jnp.float32)
+    # grouped expert FFN at Lite widths: a decode bucket of 4 lanes, top-6
+    # over 12 slots of a 16-slot buffer
+    from repro.kernels.expert_ffn import pack_slots, unpack_slots
+    n, k, d, f, slots = 4, 6, 2048, 1408, 16
+    idx = np.stack([rng.choice(12, k, replace=False)
+                    for _ in range(n)]).astype(np.int32)
+    ids, pos = unpack_slots(jnp.asarray(pack_slots(idx, n)), n, k)
+    x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+    w = jnp.asarray(rng.random((n, k)), jnp.float32)
+    wg = jnp.asarray(rng.normal(size=(slots, d, f)) * 0.02, jnp.float32)
+    wu = jnp.asarray(rng.normal(size=(slots, d, f)) * 0.02, jnp.float32)
+    wd = jnp.asarray(rng.normal(size=(slots, f, d)) * 0.02, jnp.float32)
+    args = (x, w, ids, pos, wg, wu, wd)
     np.testing.assert_allclose(
-        np.asarray(ref.expert_ffn_ref(x, w, wg, wu, wd)),
-        np.asarray(ops.expert_ffn(x, w, wg, wu, wd, backend="pallas")),
+        np.asarray(ops.expert_ffn(*args, backend="jnp")),
+        np.asarray(ops.expert_ffn(*args, backend="pallas")),
         rtol=2e-3, atol=2e-4)
-    out["expert_ffn_ref_us"] = _time(
-        lambda *a: ref.expert_ffn_ref(*a), x, w, wg, wu, wd)
+    out["expert_ffn_jnp_us"] = _time(
+        lambda *a: ops.expert_ffn(*a, backend="jnp"), *args)
     out["expert_ffn_pallas_interp_us"] = _time(
-        lambda *a: ops.expert_ffn(*a, backend="pallas"), x, w, wg, wu, wd)
+        lambda *a: ops.expert_ffn(*a, backend="pallas"), *args)
 
     # flash decode at 32k cache
     s, kvh, g, hd = 32768, 8, 4, 128

@@ -9,8 +9,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels import expert_ffn as _effn
 from repro.kernels import ref
-from repro.kernels.expert_ffn import expert_ffn as _expert_ffn_pallas
 from repro.kernels.flash_attention import flash_decode as _flash_pallas
 from repro.kernels.topk_gating import topk_gating as _topk_pallas
 
@@ -21,11 +21,21 @@ def topk_gating(logits: jnp.ndarray, k: int, backend: str = "jnp"):
     return _topk_pallas(logits, k, interpret=(backend != "tpu"))
 
 
-def expert_ffn(x, weights, wg, wu, wd, backend: str = "jnp"):
+def expert_ffn(x, weights, ids, pos, wg, wu, wd, backend: str = "jnp"):
+    """Grouped expert FFN over slot buffers (kernels/expert_ffn.py).
+
+    x: (N, D); weights: (N, k) router weights; ids: (U,) sorted distinct
+    slot ids padded by repeating the last; pos: (N, k) each pair's
+    position in ids; wg/wu: (S, D, F); wd: (S, F, D) -> (N, D) in x's
+    dtype. backend "jnp" runs the jnp grouped twin, "pallas" the kernel
+    in interpret mode, "tpu" the compiled kernel.
+    """
     if backend == "jnp":
-        return ref.expert_ffn_ref(x, weights, wg, wu, wd)
-    return _expert_ffn_pallas(x, weights, wg, wu, wd,
-                              interpret=(backend != "tpu"))
+        y = _effn.grouped_expert_ffn_jnp(x, ids, wg, wu, wd)
+    else:
+        y = _effn.grouped_expert_ffn(x, ids, wg, wu, wd,
+                                     interpret=(backend != "tpu"))
+    return _effn.combine(y, pos, weights).astype(x.dtype)
 
 
 def flash_decode(q, k, v, valid_len, backend: str = "jnp"):

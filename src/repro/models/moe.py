@@ -95,7 +95,7 @@ def expert_group_ffn(wg, wu, wd, x):
     """ONE expert's SwiGLU FFN over a shipped token group — the unit of
     work a peer shard computes in the expert-parallel dispatch path
     (launch/sharding.expert_dispatch_ffn; serving engines model the same
-    computation through their slot-gather program).
+    computation through their grouped slot-buffer program).
 
     wg/wu: (D, F); wd: (F, D); x: (N, D) token activations. Returns the
     (N, D) *unweighted* expert outputs — the router's top-k combine

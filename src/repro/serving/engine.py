@@ -10,7 +10,8 @@ compute (offload.OverlapTracker charges only the un-overlapped remainder
 as stall). At each MoE layer the router reveals the truth, misses are
 demand-fetched, every expert needed by any in-flight request is *pinned*
 in the ExpertCache for the duration of the expert compute, and the expert
-FFN runs from the slot buffer via the gather path (kernels/expert_ffn).
+FFN reads each distinct slot of the tier-0 slot buffer once, in place
+(kernels/expert_ffn.py).
 
 ``OffloadEngine`` keeps the original batch-1 API on top of the shared
 ``DecodeCore``; ``serving/scheduler.py`` builds the multi-request
@@ -43,6 +44,8 @@ import numpy as np
 from repro.core.metrics import LatencyStats, f1_over_window
 from repro.core.policies import PerRequestPolicy, Policy
 from repro.core.tracing import moe_layer_ids
+from repro.kernels import ops
+from repro.kernels.expert_ffn import pack_slots, unpack_slots
 from repro.models import attention as attn_mod
 from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
@@ -293,7 +296,8 @@ class DecodeCore:
     """
 
     def __init__(self, model, params, capacity: int, eviction: str = "lru",
-                 host_bw: float = 100e9, expert_backend: str = "jnp",
+                 host_bw: float = 100e9,
+                 expert_backend: Optional[str] = None,
                  max_batch: int = 1,
                  layer_compute_s: Union[float, str] = 0.0,
                  max_prefill_chunk: int = 8,
@@ -307,7 +311,11 @@ class DecodeCore:
         self.kinds = cfg.layer_kinds()
         self.moe_layers = moe_layer_ids(cfg)
         self.moe_index = {li: i for i, li in enumerate(self.moe_layers)}
-        self.expert_backend = expert_backend
+        from repro.kernels.runtime import default_kernel_backend
+        # expert FFN: the grouped Pallas kernel on TPU, its jnp twin
+        # elsewhere; an explicit backend string wins
+        self.expert_backend = (default_kernel_backend()
+                               if expert_backend is None else expert_backend)
         self.max_batch = max_batch
         self.scratch_row = max_batch
         self.max_prefill_chunk = max_prefill_chunk
@@ -315,7 +323,6 @@ class DecodeCore:
         # the jitted paged programs, None for the gather parity route, or
         # "auto" for the backend-appropriate default (ServeConfig holds the
         # same rule at the scheduler level and passes the resolved value)
-        from repro.kernels.runtime import default_kernel_backend
         self.kernel = default_kernel_backend() if kernel == "auto" else kernel
         # telemetry: a pure observer every subsystem below shares. The
         # default is the module-wide no-op singleton, so un-instrumented
@@ -513,21 +520,15 @@ class DecodeCore:
             return h, w, idx
 
         @jax.jit
-        def expert_from_slots(x_norm, weights, slot_idx, wg_buf, wu_buf,
+        def expert_from_slots(x_norm, weights, slots, wg_buf, wu_buf,
                               wd_buf, shared, x):
-            # x_norm/x: (N,1,D); weights: (N,1,k); slot_idx: (N,k)
-            from repro.kernels import ops
-            n, k = slot_idx.shape
-            flat = slot_idx.reshape(-1)
-            wg = jnp.take(wg_buf, flat, 0).reshape((n, k) + wg_buf.shape[1:])
-            wu = jnp.take(wu_buf, flat, 0).reshape((n, k) + wu_buf.shape[1:])
-            wd = jnp.take(wd_buf, flat, 0).reshape((n, k) + wd_buf.shape[1:])
-
-            def row(hr, wr, g, u, d):
-                return ops.expert_ffn(hr, wr, g, u, d,
-                                      backend=expert_backend)
-
-            y = jax.vmap(row)(x_norm[:, 0, :], weights[:, 0, :], wg, wu, wd)
+            # x_norm/x: (N,1,D); weights: (N,1,k); slots: pack_slots's
+            # (2*N*k,) distinct slot ids and per-pair positions
+            n, _, k = weights.shape
+            ids, pos = unpack_slots(slots, n, k)
+            y = ops.expert_ffn(x_norm[:, 0, :], weights[:, 0, :], ids, pos,
+                               wg_buf, wu_buf, wd_buf,
+                               backend=expert_backend)
             out = x + y[:, None, :]
             if shared is not None:
                 out = out + ffn_apply(shared, x_norm, "swiglu")
@@ -754,7 +755,7 @@ class DecodeCore:
         count. Shipped experts bypass the ExpertCache entirely (no
         access, no insert, no pin): their weights are staged in ephemeral
         slot rows modeling the peer's copy, the round trip is charged to
-        the ship channel, and the SAME jitted slot-gather program computes
+        the ship channel, and the SAME jitted expert program computes
         them — so streams stay token-identical while tier 0 is untouched.
         """
         ship_slot: Dict = {}
@@ -835,7 +836,14 @@ class DecodeCore:
             slot_idx[i] = [
                 ship_slot[key] if key in ship_slot else slot_table[key]
                 for key in ((mi, int(e)) for e in idx_np[i])]
-        x = self._expert(h, w, jnp.asarray(slot_idx), self.slots.w_gate,
+        # the call reads each distinct slot once: its sorted ids and each
+        # live pair's position among them travel as one array
+        slots = pack_slots(slot_idx, n_real)
+        if tel_on:
+            self.tel.counter("expert.pairs", n_real * idx_np.shape[1])
+            self.tel.counter("expert.distinct",
+                             np.unique(slot_idx[:n_real]).size)
+        x = self._expert(h, w, jnp.asarray(slots), self.slots.w_gate,
                          self.slots.w_up, self.slots.w_down,
                          lp["moe"].get("shared"), x)
         for key in pinned:
@@ -1011,7 +1019,7 @@ class DecodeCore:
                                          x)                     # (1,cb,...)
                 with self.tel.span(PID_ENGINE, 1, "driver.route_sync"):
                     idx_np = np.asarray(idx)[0]                 # (cb, k)
-                # chunk tokens become the expert units: same gather path,
+                # chunk tokens become the expert units: same expert program,
                 # same pinning discipline as decode lanes
                 hu = h[0][:, None, :]
                 wu = w[0][:, None, :].astype(x.dtype)
@@ -1048,7 +1056,8 @@ class OffloadEngine:
 
     def __init__(self, model, params, policy: Optional[Policy],
                  capacity: int, eviction: str = "lru",
-                 host_bw: float = 100e9, expert_backend: str = "jnp",
+                 host_bw: float = 100e9,
+                 expert_backend: Optional[str] = None,
                  layer_compute_s: Union[float, str] = 0.0, tiers=None,
                  telemetry=None):
         self.core = DecodeCore(model, params, capacity, eviction, host_bw,

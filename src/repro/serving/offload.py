@@ -294,7 +294,7 @@ class SlotBuffer:
     exactly one expert-FFN program — the rows model the peer's own copy,
     are never registered in ``slot_of``/the ExpertCache, charge no fetch
     bytes, and are overwritten freely by the next step's shipped group.
-    Running the shipped experts through the SAME jitted slot-gather
+    Running the shipped experts through the SAME jitted expert
     program as resident ones is what keeps fetch/ship streams bit
     identical."""
 
@@ -377,10 +377,6 @@ class SlotBuffer:
         return (jnp.take(self.w_gate, slots, 0),
                 jnp.take(self.w_up, slots, 0),
                 jnp.take(self.w_down, slots, 0))
-
-    def slot_ids(self, keys) -> np.ndarray:
-        """Host-side slot indices for resident keys (batched gather path)."""
-        return np.asarray([self.slot_of[k] for k in keys], np.int32)
 
 
 def make_offload_cache(store: HostExpertStore, capacity: int,

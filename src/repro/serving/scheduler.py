@@ -166,7 +166,7 @@ class BatchedOffloadEngine:
 
     def __init__(self, model, params, policy: PolicySpec, capacity: int,
                  eviction: str = "lru", host_bw: float = 100e9,
-                 expert_backend: str = "jnp", max_batch: int = 4,
+                 expert_backend: Optional[str] = None, max_batch: int = 4,
                  layer_compute_s=0.0, paged: bool = True,
                  block_size: int = 8, kv_blocks: Optional[int] = None,
                  prefill_chunk: int = 8, use_kernel: bool = True,

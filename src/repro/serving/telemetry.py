@@ -83,6 +83,10 @@ METRICS = {
                       "(peer/disk)",
     "cache.evictions": "tier-0 slot evictions (provenance in the "
                        "eviction instant events)",
+    "expert.pairs": "live (unit, expert) pairs the expert FFN computed",
+    "expert.distinct": "distinct tier-0 slots the expert FFN read "
+                       "(pairs / distinct = the weight reads grouping "
+                       "saves)",
     "kv.blocks_in_use": "KV pool blocks currently allocated (gauge)",
     "prefix.adopted_blocks": "prefix-cache blocks adopted at admission "
                              "or chunk-boundary extension",

@@ -28,20 +28,51 @@ def test_topk_gating(t, e, k, dtype):
     np.testing.assert_allclose(np.asarray(wp).sum(-1), 1.0, atol=1e-3)
 
 
-@pytest.mark.parametrize("k,d,f", [
-    (1, 128, 128), (2, 128, 256), (6, 256, 512), (4, 512, 1024),
-    (2, 128, 1408),      # DeepSeek-V2-Lite's expert width: 11 128-blocks
+def _routing(n, n_real, k, slots, n_experts, seed):
+    """(n, k) slot ids: each live unit draws k distinct slots out of
+    ``n_experts`` rows of a buffer of ``slots``, so units share slots;
+    pad units (past n_real) hold slot 0, which no live unit may use."""
+    rng = np.random.default_rng(seed)
+    pool = rng.choice(np.arange(1, slots), n_experts, replace=False)
+    idx = np.zeros((n, k), np.int32)
+    for i in range(n_real):
+        idx[i] = pool[rng.choice(n_experts, k, replace=False)]
+    return idx
+
+
+@pytest.mark.parametrize("n,n_real,k,d,f,slots,n_experts,block_f", [
+    (1, 1, 2, 128, 128, 4, 3, None),      # one decode lane
+    (4, 4, 6, 256, 512, 16, 8, None),     # duplicates across units
+    (4, 2, 2, 128, 256, 8, 4, None),      # two pad units, no live pairs
+    (8, 8, 6, 128, 1408, 48, 12, None),   # DSV2-Lite's expert width
+    (8, 5, 2, 128, 1408, 24, 6, 128),     # 11 128-wide F tiles, pads
+    (2, 2, 2, 128, 256, 256, 3, None),    # buffer far larger than U
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_expert_ffn(k, d, f, dtype):
-    x = jnp.asarray(RNG.normal(size=(d,)), dtype)
-    w = jnp.asarray(RNG.random(k) + 0.1, jnp.float32)
-    wg = jnp.asarray(RNG.normal(size=(k, d, f)) * 0.05, dtype)
-    wu = jnp.asarray(RNG.normal(size=(k, d, f)) * 0.05, dtype)
-    wd = jnp.asarray(RNG.normal(size=(k, f, d)) * 0.05, dtype)
-    yr = np.asarray(ref.expert_ffn_ref(x, w, wg, wu, wd), np.float32)
-    yp = np.asarray(ops.expert_ffn(x, w, wg, wu, wd, backend="pallas"),
-                    np.float32)
+def test_expert_ffn(n, n_real, k, d, f, slots, n_experts, block_f, dtype):
+    """The grouped kernel (interpret mode) against the per-pair float32
+    oracle: each live unit's k experts gathered and run one by one."""
+    from repro.kernels.expert_ffn import (combine, grouped_expert_ffn,
+                                          pack_slots, unpack_slots)
+    idx = _routing(n, n_real, k, slots, n_experts, seed=n * 131 + f)
+    x = jnp.asarray(RNG.normal(size=(n, d)), dtype)
+    w = jnp.asarray(RNG.random((n, k)) + 0.1, dtype)
+    wg = jnp.asarray(RNG.normal(size=(slots, d, f)) * 0.05, dtype)
+    wu = jnp.asarray(RNG.normal(size=(slots, d, f)) * 0.05, dtype)
+    wd = jnp.asarray(RNG.normal(size=(slots, f, d)) * 0.05, dtype)
+    meta = pack_slots(idx, n_real)
+    ids, pos = unpack_slots(jnp.asarray(meta), n, k)
+    distinct = np.unique(idx[:n_real])
+    np.testing.assert_array_equal(np.asarray(ids)[:distinct.size], distinct)
+    assert np.all(np.asarray(ids)[distinct.size:] == distinct[-1])
+    y = grouped_expert_ffn(x, ids, wg, wu, wd, block_f=block_f,
+                           interpret=True)
+    # pad steps compute nothing
+    assert not np.asarray(y)[distinct.size:].any()
+    yp = np.asarray(combine(y, pos, w).astype(dtype), np.float32)[:n_real]
+    yr = np.stack([np.asarray(ref.expert_ffn_ref(
+        x[i], w[i], wg[idx[i]], wu[idx[i]], wd[idx[i]]), np.float32)
+        for i in range(n_real)])
     tol = 5e-4 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(yr, yp, rtol=tol, atol=tol)
 

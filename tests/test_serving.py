@@ -109,6 +109,62 @@ def test_engine_pallas_expert_backend(backbone):
         np.testing.assert_allclose(l1, l2, rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_expert_from_slots_matches_per_pair(backbone, backend):
+    """The engine's grouped expert program against the per-pair
+    formulation it replaced: a weight copy gathered for every (unit, k)
+    pair, the float32 oracle per unit, the shared experts added."""
+    from repro.kernels import ref
+    from repro.kernels.expert_ffn import pack_slots
+    from repro.models.common import ffn_apply
+    from repro.serving.engine import DecodeCore
+    cfg, model, params, _ = backbone
+    k = cfg.moe.top_k
+    core = DecodeCore(model, params, 8, expert_backend=backend, max_batch=4)
+    lp = core.layers[moe_layer_ids(cfg)[0]]
+    rng = np.random.default_rng(3)
+    s, d, f = core.slots.w_gate.shape
+    bufs = [jnp.asarray(rng.normal(size=shape) * 0.05, jnp.float32)
+            for shape in ((s, d, f), (s, d, f), (s, f, d))]
+    h = jnp.asarray(rng.normal(size=(4, 1, d)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(4, 1, d)), jnp.float32)
+    w = jnp.asarray(rng.random((4, 1, k)), jnp.float32)
+    slot_idx = np.array([[0, 5], [5, 2], [7, 0], [0, 0]], np.int32)[:, :k]
+    n_real = 3
+    out = np.asarray(core._expert(h, w, jnp.asarray(pack_slots(slot_idx,
+                                                               n_real)),
+                                  *bufs, lp["moe"].get("shared"), x))
+    for i in range(n_real):
+        g, u, dn = (b[slot_idx[i]] for b in bufs)
+        want = x[i, 0] + ref.expert_ffn_ref(h[i, 0], w[i, 0], g, u, dn)
+        if lp["moe"].get("shared") is not None:
+            want = want + ffn_apply(lp["moe"]["shared"], h[i], "swiglu")[0]
+        np.testing.assert_allclose(out[i, 0], np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_expert_counters_on_fixed_routing(backbone):
+    """``expert.pairs`` counts the live (unit, k) pairs of a call and
+    ``expert.distinct`` the slots it reads: pad units add neither."""
+    from repro.serving.engine import DecodeCore
+    from repro.serving.telemetry import Telemetry
+    cfg, model, params, _ = backbone
+    tel = Telemetry()
+    n_total = len(moe_layer_ids(cfg)) * cfg.moe.num_experts
+    core = DecodeCore(model, params, n_total, max_batch=4, telemetry=tel)
+    lp = core.layers[moe_layer_ids(cfg)[0]]
+    d = cfg.d_model
+    idx = np.array([[0, 1], [1, 2], [3, 0], [9, 10]], np.int32)
+    h = jnp.zeros((4, 1, d), jnp.float32)
+    w = jnp.full((4, 1, 2), 0.5, jnp.float32)
+    core._moe_units(0, lp, h, w, h, idx, n_real=3)
+    assert tel.total("expert.pairs") == 6
+    assert tel.total("expert.distinct") == 4       # experts 0-3, not 9, 10
+    core._moe_units(0, lp, h, w, h, idx, n_real=1)
+    assert tel.total("expert.pairs") == 8
+    assert tel.total("expert.distinct") == 6
+
+
 def test_host_only_routed_experts_match_decode_step():
     """``init_offload_params`` draws ``lm_init``'s tree with every routed
     expert stack on the host; an engine built from it keeps routed experts
